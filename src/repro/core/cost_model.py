@@ -127,7 +127,6 @@ def profile_ops(
     elem_elems: int = 1 << 22,
     attn_shape: tuple = (1, 128, 2, 32),
     repeats: int = 3,
-    include_attention: bool = True,
 ) -> OpProfile:
     """Time representative ops on the current backend and fit the rates.
 
@@ -155,20 +154,16 @@ def profile_ops(
     t_ew = _time_call(ew, x, repeats=repeats)
     sec_per_byte = t_ew / (4.0 * elem_elems * 4)
 
-    # --- attention kernel ----------------------------------------------------
-    sec_per_flop_attn = sec_per_flop_mm * 2.0  # fallback: half matmul rate
-    if include_attention:
-        try:
-            from repro.kernels import flash_attention
+    # --- attention kernel: a kernel that fails to run raises, rather than
+    # leave the DP a rate nobody measured -------------------------------------
+    from repro.kernels import flash_attention
 
-            B, S, H, D = attn_shape
-            q = jax.random.normal(key, (B, S, H, D), jnp.float32)
-            fa = jax.jit(lambda qq: flash_attention(qq, qq, qq, causal=True))
-            t_fa = _time_call(fa, q, repeats=max(1, repeats - 1))
-            attn_flops = 4.0 * B * H * S * S * D  # qk^T + pv
-            sec_per_flop_attn = t_fa / attn_flops
-        except Exception:
-            pass  # interpret-mode kernel unavailable → keep the fallback rate
+    B, S, H, D = attn_shape
+    q = jax.random.normal(key, (B, S, H, D), jnp.float32)
+    fa = jax.jit(lambda qq: flash_attention(qq, qq, qq, causal=True))
+    t_fa = _time_call(fa, q, repeats=max(1, repeats - 1))
+    attn_flops = 4.0 * B * H * S * S * D  # qk^T + pv
+    sec_per_flop_attn = t_fa / attn_flops
 
     return OpProfile(
         sec_per_flop_matmul=float(sec_per_flop_mm),

@@ -14,10 +14,16 @@ with MXU-aligned (multiple-of-128) matmul dims; the kv loop is the innermost
 accumulator lives across grid steps instead of in shared memory).
 
 Layouts: q (B, H, Sq, D);  k, v (B, KV, Sk, D) with KV | H (GQA: the kv-head
-index map is h → h·KV/H).  All matmuls accumulate in f32.
+index map is h → h·KV/H).  Matmuls take the operands in their own dtype and
+accumulate in f32.  Row statistics (lse, delta) cross HBM as (B, H, Sq, 1):
+Mosaic wants the last two block dims divisible by (8, 128) or equal to the
+array's, so a (1, 1, bq) block of a (B, H, Sq) array is refused, while a
+(1, 1, bq, 1) block is accepted and reads back as a (bq, 1) column that
+broadcasts across the score tile's lanes.  Inside the kernels every value
+stays 2-D (Mosaic has no 1-D vector layout).
 
-Validated in interpret mode against kernels.ref on CPU; on TPU the same
-pallas_call lowers to Mosaic.
+Validated in interpret mode against kernels.ref on CPU; compiled for v5e in
+tests/test_tpu_compile.py.
 """
 
 from __future__ import annotations
@@ -30,12 +36,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # TPU scratch memory spaces; interpret mode accepts them on CPU too
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover - very old jax
-    _VMEM = None
+_VMEM = pltpu.VMEM
 
 NEG_INF = -1e30  # large-negative instead of -inf: keeps masked exp() exact 0
                  # without nan from (-inf) - (-inf)
@@ -54,10 +57,10 @@ def _fwd_kernel(
     k_ref,  # (1, 1, bk, D)
     v_ref,  # (1, 1, bk, D)
     o_ref,  # (1, 1, bq, D)
-    lse_ref,  # (1, 1, bq)
+    lse_ref,  # (1, 1, bq, 1)
     acc_ref,  # scratch (bq, D) f32
-    m_ref,  # scratch (bq, 128) f32
-    l_ref,  # scratch (bq, 128) f32
+    m_ref,  # scratch (bq, 1) f32
+    l_ref,  # scratch (bq, 1) f32
     *,
     causal: bool,
     sm_scale: float,
@@ -83,9 +86,9 @@ def _fwd_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (bq, D)
-        k = k_ref[0, 0].astype(jnp.float32)  # (bk, D)
-        v = v_ref[0, 0].astype(jnp.float32)
+        q = q_ref[0, 0]  # (bq, D)
+        k = k_ref[0, 0]  # (bk, D)
+        v = v_ref[0, 0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale  # (bq, bk)
@@ -94,25 +97,23 @@ def _fwd_kernel(
             kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos + off >= kpos, s, NEG_INF)
 
-        m_prev = m_ref[:, 0]  # (bq,)
-        m_cur = jnp.max(s, axis=-1)  # (bq,)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)  # (bq,)
-        p = jnp.exp(s - m_new[:, None])  # (bq, bk)
-        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        m_prev = m_ref[...]  # (bq, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)  # (bq, 1)
+        p = jnp.exp(s - m_new)  # (bq, bk)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        m_ref[...] = m_new
 
     @pl.when(ik == nk - 1)
     def _finalize():
-        l = l_ref[:, 0]
+        l = l_ref[...]  # (bq, 1)
         l_safe = jnp.where(l > 0.0, l, 1.0)
-        o_ref[0, 0] = (acc_ref[...] / l_safe[:, None]).astype(o_ref.dtype)
-        m = m_ref[:, 0]
-        lse = jnp.where(l > 0.0, m + jnp.log(l_safe), NEG_INF)
+        o_ref[0, 0] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        lse = jnp.where(l > 0.0, m_ref[...] + jnp.log(l_safe), NEG_INF)
         lse_ref[0, 0] = lse.astype(lse_ref.dtype)
 
 
@@ -148,8 +149,8 @@ def flash_attention_fwd(
     grid = (B, H, nq, nk)
     scratch = [
         _VMEM((block_q, D), jnp.float32),
-        _VMEM((block_q, 128), jnp.float32),
-        _VMEM((block_q, 128), jnp.float32),
+        _VMEM((block_q, 1), jnp.float32),
+        _VMEM((block_q, 1), jnp.float32),
     ]
     out, lse = pl.pallas_call(
         kernel,
@@ -165,16 +166,16 @@ def flash_attention_fwd(
         ],
         out_specs=[
             pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0)),
-            pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq)),
+            pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v)
-    return out, lse
+    return out, lse[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -199,12 +200,12 @@ def _bwd_dq_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]  # (bq,)
-        delta = delta_ref[0, 0]  # (bq,) rowsum(do * o)
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0]  # (bq, 1)
+        delta = delta_ref[0, 0]  # (bq, 1) rowsum(do * o)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale
@@ -212,13 +213,14 @@ def _bwd_dq_kernel(
             qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos + off >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])  # recomputed probabilities
+        p = jnp.exp(s - lse)  # recomputed probabilities
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )  # (bq, bk)
-        ds = p * (dp - delta[:, None]) * sm_scale
+        ds = p * (dp - delta) * sm_scale
         dq_acc_ref[...] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
 
     @pl.when(ik == nk - 1)
@@ -245,12 +247,12 @@ def _bwd_dkv_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        q = q_ref[0, 0]
+        k = k_ref[0, 0]
+        v = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0]  # (bq, 1)
+        delta = delta_ref[0, 0]  # (bq, 1)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         ) * sm_scale
@@ -258,16 +260,18 @@ def _bwd_dkv_kernel(
             qpos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             kpos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
             s = jnp.where(qpos + off >= kpos, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])  # (bq, bk) recomputed
+        p = jnp.exp(s - lse)  # (bq, bk) recomputed
         dv_acc_ref[...] += jax.lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )  # pᵀ · do  (bk, D)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
         )
-        ds = p * (dp - delta[:, None]) * sm_scale  # (bq, bk)
+        ds = p * (dp - delta) * sm_scale  # (bq, bk)
         dk_acc_ref[...] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )  # dsᵀ · q  (bk, D)
 
     @pl.when(iq == nq - 1)
@@ -296,8 +300,9 @@ def flash_attention_bwd(
     nq, nk = Sq // block_q, Sk // block_k
     sm_scale = 1.0 / math.sqrt(D)
     delta = jnp.sum(
-        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1
-    )  # (B, H, Sq)
+        do.astype(jnp.float32) * out.astype(jnp.float32), axis=-1, keepdims=True
+    )  # (B, H, Sq, 1)
+    lse = lse[..., None]  # (B, H, Sq, 1): see the module note on row layouts
 
     kw = dict(
         causal=causal, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
@@ -306,7 +311,7 @@ def flash_attention_bwd(
 
     q_spec_q = pl.BlockSpec((1, 1, block_q, D), lambda b, h, iq, ik: (b, h, iq, 0))
     k_spec_q = pl.BlockSpec((1, 1, block_k, D), lambda b, h, iq, ik: (b, h, ik, 0))
-    r_spec_q = pl.BlockSpec((1, 1, block_q), lambda b, h, iq, ik: (b, h, iq))
+    r_spec_q = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, iq, ik: (b, h, iq, 0))
 
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, **kw),
@@ -321,7 +326,7 @@ def flash_attention_bwd(
     # dk/dv: kv block is the carried tile; q blocks iterate innermost
     q_spec_k = pl.BlockSpec((1, 1, block_q, D), lambda b, h, ik, iq: (b, h, iq, 0))
     k_spec_k = pl.BlockSpec((1, 1, block_k, D), lambda b, h, ik, iq: (b, h, ik, 0))
-    r_spec_k = pl.BlockSpec((1, 1, block_q), lambda b, h, ik, iq: (b, h, iq))
+    r_spec_k = pl.BlockSpec((1, 1, block_q, 1), lambda b, h, ik, iq: (b, h, iq, 0))
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, **kw),

@@ -56,7 +56,6 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     from repro.configs import SHAPES, get_config, shape_applicable
     from repro.launch.mesh import make_production_mesh, mesh_num_devices
     from repro.launch.steps import build_step, segment_plan
-    from repro.parallel.compat import set_mesh
 
     cfg = get_config(arch)
     shape = SHAPES[shape_name]
@@ -76,7 +75,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
     }
     if opts:
         rec["opts"] = list(opts)
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         fn, in_sh, out_sh, example = build_step(cfg, shape, mesh, opts=opts)
         sp, plan_res = (segment_plan(cfg, shape, mesh)
                         if shape.kind == "train" else (None, None))

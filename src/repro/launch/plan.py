@@ -311,6 +311,7 @@ class SegmentPlan:
     sizes: Tuple[int, ...]
     remat: Tuple[bool, ...]
     n_micro: int = 1
+    budget: float = 0.0  # per-device activation bytes the DP planned against
 
     @property
     def n_segments(self) -> int:
@@ -361,11 +362,11 @@ def plan_unit_segments(
     res = get_default_planner().solve(g, B, "exact_dp", objective)
     if not res.feasible:
         sp = SegmentPlan(tuple(1 for _ in range(pi.n_units)),
-                         tuple(True for _ in range(pi.n_units)), n_micro)
+                         tuple(True for _ in range(pi.n_units)), n_micro, B)
         return sp, res
     _maybe_verify(g, res, B)
     sizes, remat = segments_from_result(res, pi.n_units)
-    return SegmentPlan(sizes, remat, n_micro), res
+    return SegmentPlan(sizes, remat, n_micro, B), res
 
 
 def prewarm_unit_plans(

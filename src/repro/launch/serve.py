@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serving import Engine
 
@@ -31,6 +32,7 @@ def main(argv=None):
                     help="shared on-disk recomputation-plan cache")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)

@@ -11,13 +11,15 @@ pass instead of being cached.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard
+from repro.parallel.sharding import resolve, shard
 from .layers import _init_normal, apply_rope
 
 
@@ -172,6 +174,35 @@ def chunked_attention(
     return out[:, :orig_S].astype(q.dtype)
 
 
+def kernel_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
+) -> jax.Array:
+    """The Pallas flash kernel, run shard by shard under the ambient mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
+    through ``shard_map`` with batch and heads split by the same rules as
+    the surrounding constraints.  When q and kv heads would not split alike
+    (GQA kv heads that do not divide the model axis) heads stay whole, so
+    each q head still finds its kv head on the same device.
+    """
+    from repro.kernels.ops import flash_attention
+
+    kernel = functools.partial(flash_attention, causal=causal)
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        return kernel(q, k, v)
+    q_spec = resolve(("batch", None, "heads", None), shape=q.shape)
+    kv_spec = resolve(("batch", None, "kv_heads", None), shape=k.shape)
+    if q_spec[2] != kv_spec[2]:
+        q_spec = P(q_spec[0], None, None, None)
+        kv_spec = P(kv_spec[0], None, None, None)
+    # check_vma=False: pallas_call out_shapes carry no varying-axes info
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v)
+
+
 def attention(
     p,
     x: jax.Array,
@@ -198,9 +229,7 @@ def attention(
         backend == "auto" and jax.default_backend() == "tpu" and S % 128 == 0
     )
     if use_kernel:
-        from repro.kernels.ops import flash_attention as _flash
-
-        ctx = _flash(q, k, v, causal=causal)
+        ctx = kernel_attention(q, k, v, causal=causal)
     elif S > chunked_threshold:
         ctx = jax.checkpoint(
             lambda q_, k_, v_: chunked_attention(q_, k_, v_, causal=causal)

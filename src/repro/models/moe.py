@@ -157,14 +157,8 @@ def moe_apply(
 ):
     """x: (B, S, D) → (B, S, D)[, aux-loss scalars]."""
     if not return_aux:
-        from repro.parallel.compat import get_abstract_mesh
-
-        try:
-            mesh = get_abstract_mesh()
-            has_mesh = mesh is not None and mesh.axis_names and not mesh.empty
-        except Exception:
-            has_mesh = False
-        if has_mesh:
+        mesh = jax.sharding.get_abstract_mesh()
+        if not mesh.empty:
             y = _moe_shard_map(p, x, cfg, mesh)
             if y is not None:
                 return y

@@ -49,7 +49,9 @@ def lr_schedule(cfg: AdamWConfig, step: jax.Array) -> jax.Array:
 
 
 def init(params: Any) -> AdamWState:
-    zeros = lambda p: jnp.zeros(p.shape, jnp.float32)
+    # zeros_like keeps each parameter's sharding: moments of a sharded
+    # model are laid out like it, not piled on the default device
+    zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
     return AdamWState(
         step=jnp.zeros((), jnp.int32),
         mu=jax.tree_util.tree_map(zeros, params),

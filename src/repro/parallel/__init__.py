@@ -1,1 +1,1 @@
-"""repro.parallel — mesh/axis-type compatibility shims and sharding rules."""
+"""repro.parallel — logical-axis sharding rules and per-device byte accounting."""

@@ -26,8 +26,6 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.parallel.compat import get_abstract_mesh
-
 
 Rules = Dict[str, Any]  # logical name -> mesh axis (str | tuple | None)
 
@@ -92,16 +90,6 @@ def get_rules() -> Rules:
     return dict(_ACTIVE_RULES)
 
 
-def _mesh_axes(mesh: Optional[Mesh]) -> Tuple[str, ...]:
-    if mesh is not None:
-        return tuple(mesh.axis_names)
-    env = get_abstract_mesh()
-    try:
-        return tuple(env.axis_names) if env is not None else ()
-    except Exception:
-        return ()
-
-
 def resolve_spec(
     logical: Sequence[Optional[str]],
     axis_sizes: Dict[str, int],
@@ -161,21 +149,13 @@ def resolve(
     shape: Optional[Sequence[int]] = None,
 ) -> P:
     """Logical names → PartitionSpec under the active rules + mesh axes."""
-    src = mesh if mesh is not None else get_abstract_mesh()
-    sizes = _axis_sizes(src)
-    for a in _mesh_axes(mesh):
-        sizes.setdefault(a, 1)
-    return resolve_spec(logical, sizes, shape=shape)
+    src = mesh if mesh is not None else jax.sharding.get_abstract_mesh()
+    return resolve_spec(logical, _axis_sizes(src), shape=shape)
 
 
 def _axis_sizes(mesh) -> Dict[str, int]:
-    try:
-        return dict(zip(mesh.axis_names, mesh.axis_sizes))
-    except Exception:
-        try:
-            return dict(mesh.shape)
-        except Exception:
-            return {}
+    """Axis name → size of a ``Mesh`` or ``AbstractMesh``."""
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
 
 
 def drop_indivisible(spec: P, shape: Tuple[int, ...], axis_sizes: Dict[str, int]) -> P:
@@ -197,18 +177,15 @@ def drop_indivisible(spec: P, shape: Tuple[int, ...], axis_sizes: Dict[str, int]
 
 
 def shard(x, *logical: Optional[str]):
-    """with_sharding_constraint by logical axis names (no-op without a mesh)."""
-    try:
-        mesh = get_abstract_mesh()
-        if mesh is None or not mesh.axis_names or mesh.empty:
-            return x
-    except Exception:
+    """with_sharding_constraint by logical axis names (no-op without a mesh).
+
+    A constraint the mesh cannot take raises: a layout that silently fell
+    back to replicated would run the model unsharded with no signal.
+    """
+    if jax.sharding.get_abstract_mesh().empty:
         return x
     spec = resolve(logical, shape=tuple(x.shape))
-    try:
-        return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 # ---------------------------------------------------------------------------

@@ -166,8 +166,15 @@ class Trainer:
             return new_params, new_opt, err_fb, metrics
 
         donate_argnums = (0, 1, 2) if donate else ()
-        kw = {}
-        return jax.jit(step_fn, donate_argnums=donate_argnums, **kw)
+        return jax.jit(step_fn, donate_argnums=donate_argnums)
+
+    def lower(self, batch: Dict[str, Any]) -> jax.stages.Lowered:
+        """The jitted step lowered for ``batch`` (arrays or
+        ShapeDtypeStructs) against the live state: ``.compile().as_text()``
+        is the program :meth:`run` executes."""
+        return self._train_step.lower(
+            self.params, self.opt_state, self.err_fb, batch
+        )
 
     # --------------------------------------------------------- run control
 
@@ -214,7 +221,7 @@ class Trainer:
         """Run to total_steps; ``batches`` is an iterable of host batches."""
         c = self.cfg
         it = iter(batches)
-        losses = []
+        losses, seconds = [], []
         while self.step < c.total_steps:
             batch = next(it)
             batch = {k: jnp.asarray(v) for k, v in batch.items()}
@@ -229,6 +236,7 @@ class Trainer:
                 self.skipped += 1
             self.step += 1
             losses.append(loss)
+            seconds.append(dt)
             if c.log_every and self.step % c.log_every == 0:
                 log(
                     f"step {self.step:6d}  loss {loss:.4f}  "
@@ -243,6 +251,7 @@ class Trainer:
         return {
             "final_loss": losses[-1] if losses else float("nan"),
             "losses": losses,
+            "step_seconds": seconds,
             "skipped": self.skipped,
             "straggler_steps": self.straggler_steps,
             "step": self.step,

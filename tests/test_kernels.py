@@ -129,3 +129,20 @@ def test_fully_masked_rows_are_zero():
     q, k, v = _mk(1, 2, 2, 128, 128, 64, jnp.float32)
     out, _ = flash_attention_fwd(q, k, v, causal=True, interpret=True)
     assert not bool(jnp.any(jnp.isnan(out)))
+
+
+def test_kernel_attention_under_mesh_matches_dense():
+    """Under a mesh the kernel runs per shard (shard_map): GSPMD cannot
+    partition a Mosaic kernel.  Same result as the XLA attention."""
+    from repro.launch.mesh import auto_mesh
+    from repro.models.attention import dense_attention, kernel_attention
+
+    B, S, H, KV, D = 2, 128, 4, 2, 32
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
+    k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
+    v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
+    with jax.sharding.set_mesh(auto_mesh((1, 1), ("data", "model"))):
+        got = jax.jit(kernel_attention)(q, k, v)
+    np.testing.assert_allclose(got, dense_attention(q, k, v), rtol=1e-5,
+                               atol=2e-5)

@@ -1,6 +1,8 @@
 """Launch-layer planning: the DP plan on the unit chain, its lowering to
 scan segments, and the invariance of the loss/grads under any plan."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -124,3 +126,57 @@ def test_long_context_uses_seq_shards():
     pi_full = plan_inputs(cfg, SHAPES["long_500k"], dp_shards=1, seq_shards=1,
                           model_shards=16)
     assert pi_local.bytes_boundary * 15 < pi_full.bytes_boundary
+
+
+# ---------------------------------------------------------------------------
+# The training launcher and its compile cache
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("env,expected", [
+    ({"JAX_COMPILATION_CACHE_DIR": "/cache/from/env"}, "/cache/from/env"),
+    ({}, None),
+], ids=["env_set", "env_unset"])
+def test_compile_cache_dir_rule(env, expected):
+    from repro.launch.compile_cache import REPO_CACHE_DIR, compile_cache_dir
+
+    got = compile_cache_dir(env)
+    assert got == (expected or str(REPO_CACHE_DIR))
+    assert REPO_CACHE_DIR.name == ".jax_cache"
+    assert (REPO_CACHE_DIR.parent / "chip_smoke.py").exists()  # the repo root
+
+
+def test_enable_compile_cache_sets_no_dir_when_env_given(monkeypatch):
+    from repro.launch import compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/cache/from/env")
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    assert compile_cache.enable_compile_cache() == "/cache/from/env"
+    assert updates == []
+
+
+def _train_main(monkeypatch, argv):
+    from repro.launch import train
+
+    monkeypatch.setattr(train, "enable_compile_cache", lambda: None)
+    return train.main(argv)
+
+
+def test_train_layers_cut_keeps_widths(monkeypatch):
+    out = _train_main(monkeypatch, "--reduced --layers 3 --steps 2 --batch 2 "
+                                   "--seq 16".split())
+    cut, full = out["config"], reduced(get_config("stablelm-3b"))
+    assert cut.n_layers == 3
+    assert cut == dataclasses.replace(full, n_layers=3)
+    assert out["plan"]["n_micro"] == 1 and sum(out["plan"]["segments"]) == 3
+    assert len(out["losses"]) == len(out["step_seconds"]) == 2
+    assert all(np.isfinite(out["losses"]))
+
+
+def test_train_refuses_a_plan_that_needs_microbatches(monkeypatch):
+    """The Trainer has no gradient accumulation: a plan with n_micro > 1
+    must stop the launcher, not run the whole batch over budget."""
+    with pytest.raises(ValueError, match="n_micro"):
+        _train_main(monkeypatch, "--batch 256 --seq 4096 --steps 1".split())

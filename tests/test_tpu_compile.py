@@ -1,0 +1,63 @@
+"""The main-path kernels compiled for a described TPU v5e (no chip needed).
+
+Interpret mode runs the kernel bodies on the CPU but never asks Mosaic:
+block shapes it refuses, 1-D vector layouts and kernels that GSPMD cannot
+partition only show when the TPU compiler itself is run.  The topology is
+described inside a fixture — never at import — so that under several test
+workers only the one given this file loads the TPU library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.ops import flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One described chip, with the persistent cache off: a compile for a
+    described device is written to it but cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+# (B, S, H, KV, D): stablelm-3b's heads, and qwen2.5-14b's GQA heads
+SHAPES = {"d80_h32": (1, 2048, 32, 32, 80), "gqa_d128_h40_kv8": (1, 2048, 40, 8, 128)}
+
+
+def _fwd(q, k, v):
+    return flash_attention(q, k, v, causal=True, interpret=False)
+
+
+def _fwd_bwd(q, k, v):
+    loss = lambda *a: jnp.sum(_fwd(*a).astype(jnp.float32))
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("fn", [_fwd, _fwd_bwd], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("shape", list(SHAPES.values()), ids=list(SHAPES))
+def test_flash_attention_compiles_for_v5e(one_chip, shape, fn):
+    B, S, H, KV, D = shape
+    sds = lambda h: jax.ShapeDtypeStruct((B, S, h, D), jnp.bfloat16,
+                                         sharding=one_chip)
+    compiled = jax.jit(fn).lower(sds(H), sds(KV), sds(KV)).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -11,7 +11,7 @@ from repro.configs import get_config, reduced
 from repro.data import DataConfig, SyntheticLM
 from repro.models import build_model
 from repro.optim.adamw import AdamWConfig
-from repro.parallel.compat import AxisType, make_mesh
+from repro.launch.mesh import auto_mesh
 from repro.train import TrainConfig, Trainer
 
 
@@ -114,8 +114,7 @@ def test_remesh_rejits(small_model):
     cfg, model, params, data = small_model
     tr = Trainer(model.loss, params, _tc(total_steps=2))
     tr.run(iter(data))
-    mesh = make_mesh((1, 1), ("data", "model"),
-                     axis_types=(AxisType.Auto,) * 2)
+    mesh = auto_mesh((1, 1), ("data", "model"))
     tr.remesh(mesh)
     out = tr.run(iter(data))
     assert out["step"] == 2  # already at total; re-jit path exercised

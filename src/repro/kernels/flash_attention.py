@@ -174,6 +174,7 @@ def flash_attention_fwd(
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
     return out, lse[..., 0]
 
@@ -321,6 +322,7 @@ def flash_attention_bwd(
         out_shape=[jax.ShapeDtypeStruct((B, H, Sq, D), q.dtype)],
         scratch_shapes=[_VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(q, k, v, do, lse, delta)[0]
 
     # dk/dv: kv block is the carried tile; q blocks iterate innermost
@@ -342,5 +344,6 @@ def flash_attention_bwd(
             _VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv

@@ -31,7 +31,21 @@ Responsibilities (each one individually testable — see tests/test_train_loop.p
   Trainer's mesh and input shardings flow into the traced carrier, the DP
   budgets **per-device** activation bytes, and the planned twin keeps the
   caller's shardings (pjit-composable).  ``in_shardings`` is then the
-  2-tuple ``(param_shardings, batch_shardings)`` matching the loss args.
+  2-tuple ``(param_shardings, batch_shardings)`` matching the loss args;
+* **tracing** — inside the jitted step the loss runs under
+  ``jax.named_scope("model")`` (so its ops carry ``jvp(model)`` and
+  ``transpose(jvp(model))`` in their op_name metadata, and recomputed ops
+  JAX's ``rematted_computation``), the AdamW update and NaN guard under
+  ``"optimizer"`` and the int8 round trip under ``"grad_compression"``.
+  Each step of :meth:`Trainer.run` is a
+  ``jax.profiler.StepTraceAnnotation("repro.train.step")`` holding the host
+  spans ``repro.train.next_batch``, ``put_batch``, ``dispatch``, ``sync``
+  and ``checkpoint``; all of it costs nothing measurable with no profiler
+  attached.  ``run`` returns per step ``step_seconds`` (fetch to sync) and
+  ``input_seconds`` (fetch and host-to-device copy), and ``compiles``, the
+  step executables built or loaded from the persistent cache during the
+  run.  ``straggler_steps`` and the ``log_every`` line are for the
+  operator.
 """
 
 from __future__ import annotations
@@ -50,6 +64,21 @@ from repro.optim.compression import (
     init_error_feedback,
     quantize_roundtrip_with_feedback,
 )
+
+#: JAX's event around every backend compile or persistent-cache load.  One
+#: listener, registered at import, serves every Trainer: ``Trainer.run``
+#: counts the events that fire inside its own dispatches.
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+
+
+def _count_compile(event: str, duration: float, **kwargs: Any) -> None:
+    global _compiles
+    if event == _COMPILE_EVENT:
+        _compiles += 1
+
+
+jax.monitoring.register_event_duration_secs_listener(_count_compile)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,12 +158,18 @@ class Trainer:
         mesh + input shardings.  Re-jitting after ``remesh`` re-plans under
         the new mesh (different per-device bytes → different digest).
         """
+        loss_fn = self.loss_fn
+
+        def model_loss(params, batch):
+            with jax.named_scope("model"):
+                return loss_fn(params, batch)
+
         if self.cfg.plan_budget is None:
-            return jax.value_and_grad(self.loss_fn)
+            return jax.value_and_grad(model_loss)
         from repro.core.lowering import plan_function
 
         return plan_function(
-            self.loss_fn, self.cfg.plan_budget,
+            model_loss, self.cfg.plan_budget,
             backend=self.cfg.plan_backend, mesh=self.mesh,
             in_shardings=self.in_shardings,
         )
@@ -147,21 +182,25 @@ class Trainer:
         def step_fn(params, opt_state, err_fb, batch):
             loss, grads = value_and_grad(params, batch)
             if compress:
-                grads, err_fb = quantize_roundtrip_with_feedback(grads, err_fb)
-            new_params, new_opt, metrics = adamw.update(
-                ocfg, grads, opt_state, params
-            )
-            # NaN guard: skip the update when loss/grad-norm is non-finite.
-            ok = jnp.isfinite(loss) & jnp.isfinite(metrics["grad_norm"])
-            sel = lambda a, b: jax.tree_util.tree_map(
-                lambda x, y: jnp.where(ok, x, y), a, b
-            )
-            new_params = sel(new_params, params)
-            new_opt = adamw.AdamWState(
-                step=jnp.where(ok, new_opt.step, opt_state.step),
-                mu=sel(new_opt.mu, opt_state.mu),
-                nu=sel(new_opt.nu, opt_state.nu),
-            )
+                with jax.named_scope("grad_compression"):
+                    grads, err_fb = quantize_roundtrip_with_feedback(
+                        grads, err_fb
+                    )
+            with jax.named_scope("optimizer"):
+                new_params, new_opt, metrics = adamw.update(
+                    ocfg, grads, opt_state, params
+                )
+                # NaN guard: skip the update when loss/grad-norm is non-finite.
+                ok = jnp.isfinite(loss) & jnp.isfinite(metrics["grad_norm"])
+                sel = lambda a, b: jax.tree_util.tree_map(
+                    lambda x, y: jnp.where(ok, x, y), a, b
+                )
+                new_params = sel(new_params, params)
+                new_opt = adamw.AdamWState(
+                    step=jnp.where(ok, new_opt.step, opt_state.step),
+                    mu=sel(new_opt.mu, opt_state.mu),
+                    nu=sel(new_opt.nu, opt_state.nu),
+                )
             metrics = dict(metrics, loss=loss, ok=ok)
             return new_params, new_opt, err_fb, metrics
 
@@ -221,37 +260,54 @@ class Trainer:
         """Run to total_steps; ``batches`` is an iterable of host batches."""
         c = self.cfg
         it = iter(batches)
-        losses, seconds = [], []
+        losses, seconds, input_seconds = [], [], []
+        compiles = 0
         while self.step < c.total_steps:
-            batch = next(it)
-            batch = {k: jnp.asarray(v) for k, v in batch.items()}
-            t0 = time.perf_counter()
-            self.params, self.opt_state, self.err_fb, m = self._train_step(
-                self.params, self.opt_state, self.err_fb, batch
-            )
-            loss = float(m["loss"])
-            dt = time.perf_counter() - t0
-            self._track_time(dt)
-            if not bool(m["ok"]):
-                self.skipped += 1
-            self.step += 1
-            losses.append(loss)
-            seconds.append(dt)
-            if c.log_every and self.step % c.log_every == 0:
-                log(
-                    f"step {self.step:6d}  loss {loss:.4f}  "
-                    f"gnorm {float(m['grad_norm']):.3f}  lr {float(m['lr']):.2e}  "
-                    f"{dt*1e3:.0f} ms"
-                    + (f"  [skipped={self.skipped}]" if self.skipped else "")
-                )
-            if self._ckpt and self.step % c.ckpt_every == 0:
-                self.save()
+            with jax.profiler.StepTraceAnnotation(
+                "repro.train.step", step_num=self.step
+            ):
+                t0 = time.perf_counter()
+                with jax.profiler.TraceAnnotation("repro.train.next_batch"):
+                    batch = next(it)
+                with jax.profiler.TraceAnnotation("repro.train.put_batch"):
+                    batch = {k: jnp.asarray(v) for k, v in batch.items()}
+                t_in = time.perf_counter() - t0
+                before = _compiles
+                with jax.profiler.TraceAnnotation("repro.train.dispatch"):
+                    self.params, self.opt_state, self.err_fb, m = self._train_step(
+                        self.params, self.opt_state, self.err_fb, batch
+                    )
+                compiles += _compiles - before
+                with jax.profiler.TraceAnnotation("repro.train.sync"):
+                    loss = float(m["loss"])
+                    ok = bool(m["ok"])
+                dt = time.perf_counter() - t0
+                self._track_time(dt)
+                if not ok:
+                    self.skipped += 1
+                self.step += 1
+                losses.append(loss)
+                seconds.append(dt)
+                input_seconds.append(t_in)
+                if c.log_every and self.step % c.log_every == 0:
+                    log(
+                        f"step {self.step:6d}  loss {loss:.4f}  "
+                        f"gnorm {float(m['grad_norm']):.3f}  lr {float(m['lr']):.2e}  "
+                        f"{dt*1e3:.0f} ms  input {t_in*1e3:.1f} ms"
+                        + (f"  [skipped={self.skipped}]" if self.skipped else "")
+                    )
+                if self._ckpt and self.step % c.ckpt_every == 0:
+                    with jax.profiler.TraceAnnotation("repro.train.checkpoint"):
+                        self.save()
         if self._ckpt:
-            self.save(wait=True)
+            with jax.profiler.TraceAnnotation("repro.train.checkpoint"):
+                self.save(wait=True)
         return {
             "final_loss": losses[-1] if losses else float("nan"),
             "losses": losses,
             "step_seconds": seconds,
+            "input_seconds": input_seconds,
+            "compiles": compiles,
             "skipped": self.skipped,
             "straggler_steps": self.straggler_steps,
             "step": self.step,

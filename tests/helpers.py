@@ -7,6 +7,8 @@ plain ``from helpers import ...`` — cross-importing between test *modules*
 """
 
 import itertools
+import sys
+from pathlib import Path
 
 from repro.core.graph import Graph
 
@@ -19,3 +21,14 @@ def brute_lower_sets(g: Graph):
             if g.is_lower_set(comb):
                 out.add(frozenset(comb))
     return out
+
+
+def phase_reader():
+    """The benchmark's reader of a compiled step's phases
+    (``chipbench/phases.py``), which the program's named scopes feed."""
+    bench = str(Path(__file__).resolve().parents[1] / "chipbench")
+    if bench not in sys.path:
+        sys.path.append(bench)
+    import phases
+
+    return phases

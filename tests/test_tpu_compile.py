@@ -7,11 +7,14 @@ described inside a fixture — never at import — so that under several test
 workers only the one given this file loads the TPU library.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from helpers import phase_reader
 from repro.kernels.ops import flash_attention
 
 
@@ -60,4 +63,35 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, fn):
     sds = lambda h: jax.ShapeDtypeStruct((B, S, h, D), jnp.bfloat16,
                                          sharding=one_chip)
     compiled = jax.jit(fn).lower(sds(H), sds(KV), sds(KV)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    names = ["flash_fwd"] + (["flash_dq", "flash_dkv"] if fn is _fwd_bwd else [])
+    for name in names:
+        assert any(name in ln for ln in kernels), name
+
+
+def _model_fwd_bwd(q, k, v):
+    def loss(*a):
+        with jax.named_scope("model"):
+            return jnp.sum(_fwd(*a).astype(jnp.float32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("shape", list(SHAPES.values()), ids=list(SHAPES))
+def test_flash_kernels_fall_in_their_phases(one_chip, shape):
+    """Under the Trainer's ``model`` scope the forward kernel reads as
+    forward and dq/dk/dv as backward, by the benchmark's phase reader."""
+    phases = phase_reader()
+    B, S, H, KV, D = shape
+    sds = lambda h: jax.ShapeDtypeStruct((B, S, h, D), jnp.bfloat16,
+                                         sharding=one_chip)
+    text = jax.jit(_model_fwd_bwd).lower(sds(H), sds(KV), sds(KV)).compile().as_text()
+    by_kernel = {}
+    for ln in text.splitlines():
+        if "tpu_custom_call" in ln:
+            kernel = re.search(r"flash_(fwd|dq|dkv)", ln).group(0)
+            by_kernel.setdefault(kernel, set()).update(phases.op_phases(ln).values())
+    assert by_kernel["flash_fwd"] == {"forward"}
+    assert by_kernel["flash_dq"] == by_kernel["flash_dkv"] == {"backward"}

@@ -1,5 +1,6 @@
 """Fault-tolerance behaviours of the training loop."""
 
+import dataclasses
 import tempfile
 
 import jax
@@ -7,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from helpers import phase_reader
 from repro.configs import get_config, reduced
 from repro.data import DataConfig, SyntheticLM
 from repro.models import build_model
@@ -157,3 +159,40 @@ def test_planned_step_matches_vanilla():
     assert out_vanilla["losses"] == out_planned["losses"]
     for a, b in zip(p_vanilla, p_planned):
         assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_each_step_records_its_time_and_input_time(small_model):
+    cfg, model, params, data = small_model
+    out = Trainer(model.loss, params, _tc(total_steps=3)).run(iter(data))
+    assert len(out["step_seconds"]) == len(out["input_seconds"]) == 3
+    for step, inp in zip(out["step_seconds"], out["input_seconds"]):
+        assert 0 < inp <= step
+
+
+def test_compiles_counts_new_step_executables_only(small_model):
+    cfg, model, params, data = small_model
+    tr = Trainer(model.loss, params, _tc(total_steps=1))
+    assert tr.run(iter(data))["compiles"] >= 1
+    tr.cfg = dataclasses.replace(tr.cfg, total_steps=3)
+    assert tr.run(iter(data))["compiles"] == 0
+    shorter = ({k: v[:, :16] for k, v in b.items()} for b in data)
+    tr.cfg = dataclasses.replace(tr.cfg, total_steps=4)
+    assert tr.run(shorter)["compiles"] >= 1
+
+
+@pytest.mark.parametrize("remat", [True, False], ids=["remat", "kept"])
+def test_compiled_step_names_its_phases(remat):
+    """The step's named scopes reach the compiled instructions: forward,
+    backward and optimizer ops in any plan, recomputed ops only where the
+    sqrt(n) segments are rematerialised."""
+    cfg = dataclasses.replace(reduced(get_config("stablelm-3b")), n_layers=4)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    loss = lambda p, b: model.loss(p, b, segment_sizes=(2, 2),
+                                   segment_remat=(remat, remat))
+    batch = {"tokens": jnp.zeros((2, 32), jnp.int32),
+             "labels": jnp.zeros((2, 32), jnp.int32)}
+    text = Trainer(loss, params, _tc()).lower(batch).compile().as_text()
+    found = set(phase_reader().op_phases(text).values())
+    assert {"forward", "backward", "optimizer"} <= found
+    assert ("recompute" in found) == remat

@@ -69,11 +69,12 @@ def flash_attention(
     k: jax.Array,  # (B, Sk, KV, D)
     v: jax.Array,
     causal: bool = True,
-    block_q: int = fa.DEFAULT_BLOCK_Q,
-    block_k: int = fa.DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
-    """Differentiable flash attention in model layout (B, S, H, D)."""
+    """Differentiable flash attention in model layout (B, S, H, D).  Tiles
+    left as None are chosen from the shape, per kernel."""
     if interpret is None:
         interpret = _auto_interpret()
     qh = q.transpose(0, 2, 1, 3)

@@ -6,6 +6,7 @@ plain ``from helpers import ...`` — cross-importing between test *modules*
 (``pytest tests/test_lower_sets.py`` alone, or xdist workers).
 """
 
+import importlib
 import itertools
 import sys
 from pathlib import Path
@@ -23,12 +24,20 @@ def brute_lower_sets(g: Graph):
     return out
 
 
-def phase_reader():
-    """The benchmark's reader of a compiled step's phases
-    (``chipbench/phases.py``), which the program's named scopes feed."""
+def _chipbench(name: str):
     bench = str(Path(__file__).resolve().parents[1] / "chipbench")
     if bench not in sys.path:
         sys.path.append(bench)
-    import phases
+    return importlib.import_module(name)
 
-    return phases
+
+def phase_reader():
+    """The benchmark's reader of a compiled step's phases
+    (``chipbench/phases.py``), which the program's named scopes feed."""
+    return _chipbench("phases")
+
+
+def kernel_reader():
+    """The benchmark's reader of the flash kernels in a compiled program
+    (``chipbench/devtrace.py``), which tells them apart by their results."""
+    return _chipbench("devtrace")

@@ -8,13 +8,14 @@ workers only the one given this file loads the TPU library.
 """
 
 import re
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from helpers import phase_reader
+from helpers import kernel_reader, phase_reader
 from repro.kernels.ops import flash_attention
 
 
@@ -43,8 +44,10 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
-# (B, S, H, KV, D): stablelm-3b's heads, and qwen2.5-14b's GQA heads
-SHAPES = {"d80_h32": (1, 2048, 32, 32, 80), "gqa_d128_h40_kv8": (1, 2048, 40, 8, 128)}
+# (B, S, H, KV, D): stablelm-3b's heads, qwen2.5-14b's GQA heads, and the
+# benchmark cells' own attention (batch 2 of stablelm-3b)
+SHAPES = {"d80_h32": (1, 2048, 32, 32, 80), "gqa_d128_h40_kv8": (1, 2048, 40, 8, 128),
+          "d80_h32_b2": (2, 2048, 32, 32, 80)}
 
 
 def _fwd(q, k, v):
@@ -64,11 +67,24 @@ def test_flash_attention_compiles_for_v5e(one_chip, shape, fn):
                                          sharding=one_chip)
     compiled = jax.jit(fn).lower(sds(H), sds(KV), sds(KV)).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text
     kernels = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
     names = ["flash_fwd"] + (["flash_dq", "flash_dkv"] if fn is _fwd_bwd else [])
+    # one kernel of each kind per attention, each told apart by its results
+    # as the benchmark's kernel reader tells them apart
+    assert len(kernels) == len(names)
     for name in names:
-        assert any(name in ln for ln in kernels), name
+        assert sum(name in ln for ln in kernels) == 1, name
+    kinds = kernel_reader().kernel_kinds(text)
+    assert Counter(kinds.values()) == Counter(n[len("flash_"):] for n in names)
+    heads = [ln.split("custom-call(")[0].split("=", 1)[1] for ln in kernels]
+    results = {re.search(r"flash_(fwd|dq|dkv)", ln).group(1):
+               re.findall(r"(\w+)\[([\d,]*)\]", head)
+               for ln, head in zip(kernels, heads)}
+    full = ",".join(map(str, (B, H, S, D)))
+    assert results["fwd"] == [("bf16", full), ("f32", f"{B},{H},{S},1")]
+    if fn is _fwd_bwd:
+        assert results["dq"] == [("bf16", full)]
+        assert results["dkv"] == [("bf16", full)] * 2
 
 
 def _model_fwd_bwd(q, k, v):
